@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import autotune, collectives
 from repro.core.grad_sync import GradSyncConfig, bucket_layout, sync_tree
 from repro.core.topology import TorusGrid, paper_table4_grid

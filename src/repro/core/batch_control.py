@@ -45,7 +45,9 @@ def build_plan(schedule: BatchSchedule, *, dataset_size: int,
     for st in schedule.stages:
         gb = st.global_batch(n_workers)
         span = st.end_epoch - st.start_epoch
-        n = math.ceil(span * dataset_size / gb)
+        # the tolerance keeps float noise in epoch boundaries (a span of
+        # k*gb/dataset_size epochs) from adding a step
+        n = math.ceil(span * dataset_size / gb - 1e-9)
         if max_steps is not None:
             n = min(n, max(0, max_steps - step))
         plans.append(StagePlan(stage=st, global_batch=gb, num_steps=n,

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size as _axis_size
 from repro.core.topology import TorusGrid
 
 AxisName = str | tuple[str, ...]
@@ -41,7 +40,7 @@ def _axis_index(axis: AxisName):
     if isinstance(axis, (tuple, list)):
         idx = jnp.int32(0)
         for a in axis:
-            idx = idx * _axis_size(a) + lax.axis_index(a)
+            idx = idx * lax.axis_size(a) + lax.axis_index(a)
         return idx
     return lax.axis_index(axis)
 
@@ -62,7 +61,7 @@ def ring_reduce_scatter(x: jax.Array, axis: AxisName) -> jax.Array:
     (standard ring convention); compose with :func:`ring_all_gather` which
     accounts for the offset.
     """
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1:
         return x
     i = _axis_index(axis)
@@ -85,7 +84,7 @@ def ring_all_gather(acc: jax.Array, axis: AxisName) -> jax.Array:
     Rank ``i`` holds global chunk ``(i + 1) % n``; after ``n - 1`` neighbor
     exchanges every rank holds the full concatenation in global order.
     """
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1:
         return acc
     i = _axis_index(axis)
@@ -111,7 +110,7 @@ def ring_all_reduce(x: jax.Array, axis: AxisName) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _rs(x, axis, lowering):
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1:
         return x
     if lowering == "xla":
@@ -122,7 +121,7 @@ def _rs(x, axis, lowering):
 
 
 def _ag(x, axis, lowering):
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1:
         return x
     if lowering == "xla":
@@ -134,7 +133,7 @@ def _ag(x, axis, lowering):
 
 
 def _ar(x, axis, lowering):
-    if _axis_size(axis) == 1:
+    if lax.axis_size(axis) == 1:
         return x
     if lowering == "xla":
         return lax.psum(x, axis)

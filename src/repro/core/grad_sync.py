@@ -43,7 +43,7 @@ is given) -- re-resolution after an elastic downgrade re-tunes for the
 degraded strategy. ``sync_tree`` / ``bucket_layout`` require the resolved
 integer.
 
-Both modes must run inside ``shard_map`` (see repro.compat) where the grid
+Both modes must run inside ``jax.shard_map`` where the grid
 axes are manual.
 """
 
@@ -54,9 +54,9 @@ from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
-from repro import compat
 from repro.core import collectives
 from repro.core.topology import TorusGrid
 
@@ -108,7 +108,7 @@ def _pad_to(x: jax.Array, multiple: int) -> jax.Array:
 def _world(grid: TorusGrid) -> int:
     size = 1
     for a in grid.axes:
-        size *= compat.axis_size(a)
+        size *= lax.axis_size(a)
     return size
 
 
@@ -116,10 +116,10 @@ def _ring_multiple(grid: TorusGrid) -> int:
     """Leading-dim divisibility required by the strategies' scatter phases."""
     x = 1
     for a in grid.h_axes:
-        x *= compat.axis_size(a)
+        x *= lax.axis_size(a)
     y = 1
     for a in grid.v_axes:
-        y *= compat.axis_size(a)
+        y *= lax.axis_size(a)
     # torus2d ring lowering reduce-scatters the 1/X chunk again over Y
     return x * y
 
@@ -381,7 +381,7 @@ def _strategy_viable(strategy: str, lowering: str, grid: TorusGrid, mesh,
                      manual_axes, down_axes=(), probe: bool = True):
     """(viable, reason). ``reason`` explains the rejection when not viable.
 
-    Three checks, cheapest first:
+    Two checks, cheapest first:
 
     1. *Down axes*: torus2d / hierarchical decompose the reduction into
        per-axis phases that map onto physical link dimensions -- a down
@@ -389,16 +389,10 @@ def _strategy_viable(strategy: str, lowering: str, grid: TorusGrid, mesh,
        lowering, psum) leave routing to the compiler/fabric and survive;
        the explicit ppermute ring lowering pins neighbor links, so it is
        rejected too.
-    2. *Partial-manual shard_map*: on jaxlib < 0.5 the SPMD partitioner
-       hard-aborts (uncatchable F-check) on scatter/gather/permute
-       collectives when some mesh axes stay auto
-       (``compat.SUPPORTS_PARTIAL_MANUAL_COLLECTIVES``); only all-reduce-
-       only strategies (psum, xla-lowered hierarchical) are safe there.
-    3. *Trace probe*: a tiny ``jax.eval_shape`` of the strategy under the
+    2. *Trace probe*: a tiny ``jax.eval_shape`` of the strategy under the
        real mesh/grid catches anything else (missing primitives, bad axis
        factorization) without allocating or compiling. Only run when the
-       shard_map is fully manual -- see (2) for why probing partial-manual
-       combos is not safe.
+       shard_map is fully manual.
     """
     down = set(down_axes) & set(grid.axes)
     if down:
@@ -411,14 +405,6 @@ def _strategy_viable(strategy: str, lowering: str, grid: TorusGrid, mesh,
 
     manual = set(manual_axes)
     partial = bool(set(mesh.axis_names) - manual)
-    if partial and not compat.SUPPORTS_PARTIAL_MANUAL_COLLECTIVES:
-        ar_only = strategy == "psum" or (strategy == "hierarchical"
-                                         and lowering == "xla")
-        if not ar_only:
-            return False, ("partial-manual shard_map on this jaxlib only "
-                           "lowers all-reduce collectives (jax >= 0.5 "
-                           "needed for scatter/gather/permute)")
-
     if probe and not partial:
         try:
             mult = 1
@@ -428,7 +414,7 @@ def _strategy_viable(strategy: str, lowering: str, grid: TorusGrid, mesh,
             def _probe_sync(x):
                 return collectives.all_reduce(x, grid, strategy, lowering)
 
-            smapped = compat.shard_map(
+            smapped = jax.shard_map(
                 _probe_sync, mesh=mesh,
                 in_specs=jax.sharding.PartitionSpec(),
                 out_specs=jax.sharding.PartitionSpec(),
@@ -563,7 +549,6 @@ def _sync_fused(grads, grid: TorusGrid, cfg: GradSyncConfig):
 
 
 def _sync_per_leaf(grads, grid: TorusGrid, cfg: GradSyncConfig):
-    from jax import lax
     leaves_p, treedef = jax.tree_util.tree_flatten_with_path(grads)
     if not leaves_p:
         return grads

@@ -1,12 +1,12 @@
 """Jit-ready wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode so
-every test validates the exact kernel body; on TPU the same call compiles
-to Mosaic. ``INTERPRET`` flips automatically off-TPU.
+On TPU the kernels compile to Mosaic. On the CPU backend they execute in
+``interpret=True`` mode, so every CPU test validates the exact kernel
+body. Any other backend is an error at import: silently interpreting
+there would report an interpreter as the device.
 """
 
 from __future__ import annotations
-
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +14,18 @@ import jax.numpy as jnp
 from repro.kernels import lars_update as _lars
 from repro.kernels import ls_xent as _lsx
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret_on(backend: str) -> bool:
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels here target TPU (Mosaic) or the CPU interpreter; "
+        f"the default backend is {backend!r}")
+
+
+INTERPRET = _interpret_on(jax.default_backend())
 
 
 def lars_update(p, g, v, *, lr, mom, eta, weight_decay, eps,

@@ -30,7 +30,6 @@ import json
 
 
 from repro.configs.shapes import SHAPES
-from repro import compat
 from repro.launch import hlo_stats
 from repro.launch.mesh import make_production_mesh
 from repro.models import transformer as T
@@ -43,7 +42,7 @@ def _cost_cfg(cfg: T.ArchConfig, k_blocks: int, seq_len: int) -> T.ArchConfig:
 
 
 def _extract(compiled):
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     coll = hlo_stats.collective_stats(compiled.as_text())
     return {
         "flops": ca.get("flops", 0.0),

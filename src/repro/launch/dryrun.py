@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import comm as comm_cfg
 from repro.configs import registry
 from repro.configs.shapes import SHAPES, ShapeConfig, long_context_variant
@@ -57,10 +56,10 @@ FSDP_ARCHS = {"llama-3.2-vision-90b", "kimi-k2-1t-a32b", "llama3-405b",
               "gemma2-27b"}
 
 
-# Strategy degradation (old-jaxlib partial-manual lowering limits, injected
-# torus-link faults) is handled by the shared fallback chain in
-# repro.core.grad_sync.resolve_sync_config; build_train records the
-# resolved strategy + downgrade events and run_one writes them to the JSON.
+# Strategy degradation (injected torus-link faults) is handled by the
+# shared fallback chain in repro.core.grad_sync.resolve_sync_config;
+# build_train records the resolved strategy + downgrade events and run_one
+# writes them to the JSON.
 
 
 def _bucket_bytes_arg(s: str):
@@ -150,11 +149,10 @@ def build_train(arch_id, cfg, shape, mesh, sync_strategy="torus2d",
                               fuse=False if fuse is None else fuse,
                               comm_dtype=comm_dtype,
                               bucket_bytes=bucket_bytes)
-        # graceful degradation: partial-manual shard_map (model axis auto)
-        # limits old jaxlib to all-reduce-only schedules, and injected
-        # torus-link faults (--inject-faults) kill the per-axis phase
-        # decompositions -- downgrade along the chain and record it
-        # rather than abort the audit (docs/robustness.md).
+        # graceful degradation: injected torus-link faults
+        # (--inject-faults) kill the per-axis phase decompositions --
+        # downgrade along the chain and record it rather than abort the
+        # audit (docs/robustness.md).
         gcfg, sync_events = grad_sync_lib.resolve_sync_config(
             gcfg, grid, mesh, dp, down_axes=down_axes, probe=False,
             params_like=params_sds, hw=comm_cfg.hw_for_mesh(mesh))
@@ -175,7 +173,7 @@ def build_train(arch_id, cfg, shape, mesh, sync_strategy="torus2d",
                 params, grads, {"momentum": mom}, lr=1.0, momentum=0.9)
             return jax.lax.pmean(loss, dp), new_p, new_m["momentum"]
 
-        smapped = compat.shard_map(
+        smapped = jax.shard_map(
             step, mesh=mesh,
             in_specs=(P(), P(), P(dp), P(dp),
                       P(dp) if vision is not None else P()),
@@ -263,16 +261,6 @@ def run_one(arch_id: str, shape_name: str, multi_pod: bool,
     sync_info = {"effective": None, "events": [], "config": None}
     t0 = time.time()
     if shape.step == "train":
-        if arch_id not in FSDP_ARCHS and \
-                not compat.SUPPORTS_PARTIAL_MANUAL_COLLECTIVES:
-            # jaxlib < 0.5's SPMD partitioner hard-aborts (F-level check,
-            # not catchable) on the transformer fwd/bwd inside a
-            # partial-manual shard_map; fail with a python error instead.
-            raise RuntimeError(
-                f"{arch_id} train dry-run needs partial-manual shard_map "
-                "support (jax >= 0.5); this jaxlib's SPMD partitioner "
-                "aborts the process on it. FSDP archs and prefill/decode "
-                "shapes are unaffected (see repro/compat.py).")
         fn, args, sync_info = build_train(arch_id, cfg, shape, mesh,
                                           sync_strategy,
                                           bucket_bytes=bucket_bytes,
@@ -289,7 +277,7 @@ def run_one(arch_id: str, shape_name: str, multi_pod: bool,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = hlo_stats.collective_stats(hlo)
 
@@ -361,8 +349,7 @@ def sweep_bucket_bytes(arch_id: str, multi_pod: bool = False,
                        max_hlo_buckets: int = 256,
                        slack: float = 0.05) -> dict:
     """Empirical bucket-size sweep: compile the *sync-only* program (fused
-    bucketed ``sync_tree`` under a fully-manual shard_map -- the
-    partial-manual train step aborts on this jaxlib, see repro/compat.py)
+    bucketed ``sync_tree`` under a fully-manual shard_map)
     at production scale for each candidate ``bucket_bytes``, audit the
     compiled HLO's independent exchanges, and pair every row with the
     alpha-beta cost model. The autotuner's pick
@@ -426,7 +413,7 @@ def sweep_bucket_bytes(arch_id: str, multi_pod: bool = False,
             def sync_only(grads, _gcfg=gcfg):
                 return sync_tree(grads, grid, _gcfg)
 
-            smapped = compat.shard_map(
+            smapped = jax.shard_map(
                 sync_only, mesh=mesh, in_specs=P(), out_specs=P(),
                 axis_names=frozenset(mesh.axis_names), check_vma=False)
             hlo = jax.jit(smapped).lower(params_sds).compile().as_text()
@@ -739,13 +726,6 @@ def main():
                     args.out, f"{arch_id}__{shape_name}__{mesh_name}.json")
                 if args.skip_existing and os.path.exists(path):
                     print(f"[SKIP] {arch_id} {shape_name} {mesh_name}")
-                    continue
-                if (SHAPES[shape_name].step == "train"
-                        and arch_id not in FSDP_ARCHS
-                        and not compat.SUPPORTS_PARTIAL_MANUAL_COLLECTIVES):
-                    print(f"[SKIP] {arch_id} {shape_name} {mesh_name}: "
-                          "partial-manual shard_map train step needs "
-                          "jax >= 0.5 on this jaxlib")
                     continue
                 try:
                     fault_plan = None

@@ -99,6 +99,20 @@ def collective_stats(hlo_text: str) -> dict:
     return out
 
 
+_KIND_RE = re.compile(
+    r"\s(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(?:-start)?\(")
+
+
+def collective_counts(hlo_text: str) -> dict[str, int]:
+    """Collective instructions per kind, tuple-shaped ones included (an
+    async ``-start``/``-done`` pair counts once)."""
+    counts = dict.fromkeys(_COLLECTIVES, 0)
+    for m in _KIND_RE.finditer(hlo_text):
+        counts[m.group(1)] += 1
+    return counts
+
+
 def collective_schedule(hlo_text: str) -> list[dict]:
     """Every collective op in program order: {kind, dtype, nbytes, group_size}.
 

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.shapes import SHAPES
-from repro import compat
 from repro.launch import hlo_stats
 from repro.launch.mesh import (cache_pspecs, dp_axes_of, make_factorized_mesh,
                                make_production_mesh, param_pspecs,
@@ -40,7 +39,7 @@ def _measure(fn, args, *, step: str, label: str, n_blocks_pair=None) -> dict:
     t0 = time.time()
     compiled = fn.lower(*args).compile()
     dt = time.time() - t0
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     coll = hlo_stats.collective_stats(compiled.as_text())
     flops = ca.get("flops", 0.0)
     bytes_acc = ca.get("bytes accessed", 0.0)
@@ -76,7 +75,7 @@ def _measure(fn, args, *, step: str, label: str, n_blocks_pair=None) -> dict:
 
 def _extract_cost(fn, args):
     c = fn.lower(*args).compile()
-    ca = compat.cost_analysis(c)
+    ca = c.cost_analysis()
     coll = hlo_stats.collective_stats(c.as_text())
     return {"flops": ca.get("flops", 0.0),
             "bytes": ca.get("bytes accessed", 0.0),

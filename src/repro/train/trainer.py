@@ -27,7 +27,7 @@ and at stage boundaries -- by default *asynchronously*, off the training
 thread (``checkpoint.AsyncCheckpointWriter``) -- resumes mid-stage from
 the newest *valid* checkpoint, and degrades the grad-sync strategy
 (torus2d -> ring -> psum) instead of aborting when the configured one
-cannot run on the current mesh/jaxlib (or a torus axis is down).
+cannot run on the current mesh (or a torus axis is down).
 
 ``run`` itself is a **supervised recovery loop** (``repro.train.elastic``,
 docs/robustness.md "Elastic recovery"): when the supervisor flags a
@@ -51,9 +51,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core import grad_sync as grad_sync_lib
 from repro.core import lars as lars_lib
@@ -338,6 +338,11 @@ class Trainer:
                     # per-stage recompile behind a dict hit.)
                     fn = make_train_step(self.loss_fn, self.mesh,
                                          self.dp_axes, run_cfg, grid=grid)
+                    # place the state as the step returns it (replicated
+                    # on the mesh): a fresh or restored host state would
+                    # otherwise compile the first step a second time
+                    state = jax.device_put(
+                        state, NamedSharding(self.mesh, P()))
                     try:
                         state = self._run_steps(
                             fn, state, run_cfg, data_fn, start_step,

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core import collectives
 from repro.core.topology import TorusGrid, factorize, select_grid

@@ -10,7 +10,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from _hyp import given, settings, strategies as st
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core.grad_sync import GradSyncConfig, sync_tree
 from repro.core.topology import TorusGrid

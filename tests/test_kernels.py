@@ -9,6 +9,17 @@ from _hyp import given, settings, strategies as st
 from repro.kernels import ops, ref
 
 
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True),
+                                               ("gpu", None)])
+def test_interpret_mode_only_on_cpu(backend, interpret):
+    """Mosaic on TPU, the interpreter on CPU, and no silent fallback."""
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret_on(backend)
+    else:
+        assert ops._interpret_on(backend) is interpret
+
+
 # ------------------------------------------------------------------ LARS --
 
 SHAPES = [(7,), (128,), (64, 64), (33, 5), (8, 9, 10), (1, 1), (300, 129)]

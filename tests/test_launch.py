@@ -158,3 +158,35 @@ def test_dryrun_results_complete():
             rec = json.load(fh)
         assert rec["cost"]["flops"] is not None
         assert rec["collectives"]["total_count"] >= 0
+
+
+def test_collective_counts_include_tuple_and_async_ops():
+    hlo = """
+  %ar = (f32[8]{0}, bf16[4]{0}) all-reduce(f32[8]{0} %a, bf16[4]{0} %b), replica_groups={{0,1}}
+  %ags = (f32[4]{0}, f32[8]{0}) all-gather-start(f32[4]{0} %c), dimensions={0}
+  %agd = f32[8]{0} all-gather-done((f32[4]{0}, f32[8]{0}) %ags)
+  %rs = f32[2]{0} reduce-scatter(f32[8]{0} %d), dimensions={0}
+"""
+    assert hlo_stats.collective_counts(hlo) == {
+        "all-reduce": 1, "all-gather": 1, "reduce-scatter": 1,
+        "all-to-all": 0, "collective-permute": 0}
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says, else to one
+    fixed, git-ignored directory in the checkout."""
+    import os
+    from repro.launch import train
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        train.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = train.use_compile_cache()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -93,6 +93,29 @@ def test_grad_sync_strategies_agree_end_to_end(mesh):
                                        rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.multidevice
+@pytest.mark.parametrize("n_devices,grid", [(1, (1, 1)), (4, (2, 2))])
+def test_resnet_job_on_device_mesh(n_devices, grid):
+    """chip_smoke.py's job and mesh builders at tiny size: two batch-control
+    stages through Trainer on one device and on four, with no downgrade."""
+    from repro.launch.train import device_mesh, resnet_job
+    mesh = device_mesh(jax.devices()[:n_devices])
+    assert mesh.devices.shape == grid
+    trainer, state, _ = resnet_job(resnet.ResNetConfig.tiny(),
+                                   per_chip_batches=(2, 4),
+                                   steps_per_stage=2, mesh=mesh)
+    state, history = trainer.run(state, log=lambda *a: None)
+
+    events = [h["event"] for h in history if h["kind"] == "event"]
+    assert "grad_sync_downgrade" not in events, events
+    assert "grad_sync_strategy_rejected" not in events, events
+    steps = [h for h in history if h["kind"] == "metric"]
+    assert [h["global_batch"] for h in steps] == [2 * n_devices] * 2 + \
+        [4 * n_devices] * 2
+    assert all(np.isfinite(h["loss"]) and h["skipped"] == 0 for h in steps)
+    assert int(state.step) == 4
+
+
 @pytest.mark.slow
 @pytest.mark.multidevice
 def test_transformer_lm_trains_with_recipe(mesh):
