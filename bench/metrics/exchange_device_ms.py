@@ -1,0 +1,5 @@
+from bench import layers
+
+
+def read(ctx):
+    return layers.exchange_ms(ctx, exposed=False)

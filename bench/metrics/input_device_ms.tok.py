@@ -1,0 +1,5 @@
+from bench import layers
+
+
+def read(ctx):
+    return layers.input_device_ms(ctx, "tokens")

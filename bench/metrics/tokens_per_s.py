@@ -1,0 +1,5 @@
+from bench import layers
+
+
+def read(ctx):
+    return layers.rate(ctx, "tokens")
