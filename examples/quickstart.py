@@ -49,7 +49,8 @@ def main():
 
     state = TrainState.create(resnet.init(jax.random.key(0), cfg))
     state, history = trainer.run(state)
-    print(f"final loss {history[-1]['loss']:.4f} after {int(state.step)} steps")
+    steps = [h for h in history if h["kind"] == "metric"]
+    print(f"final loss {steps[-1]['loss']:.4f} after {int(state.step)} steps")
 
 
 if __name__ == "__main__":

@@ -68,7 +68,8 @@ def main():
 
     state = TrainState.create(T.init(jax.random.key(0), cfg))
     state, history = trainer.run(state)
-    print(f"loss {history[0]['loss']:.3f} -> {history[-1]['loss']:.3f} "
+    steps = [h for h in history if h["kind"] == "metric"]
+    print(f"loss {steps[0]['loss']:.3f} -> {steps[-1]['loss']:.3f} "
           f"over {int(state.step)} steps")
 
 

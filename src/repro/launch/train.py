@@ -180,7 +180,8 @@ def main():
           f"with sync={args.sync} schedule={args.schedule}")
     state = TrainState.create(T.init(jax.random.key(0), cfg))
     state, history = trainer.run(state)
-    print(f"done: loss {history[0]['loss']:.3f} -> {history[-1]['loss']:.3f}")
+    steps = [h for h in history if h["kind"] == "metric"]
+    print(f"done: loss {steps[0]['loss']:.3f} -> {steps[-1]['loss']:.3f}")
 
 
 if __name__ == "__main__":

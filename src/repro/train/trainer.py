@@ -43,9 +43,11 @@ for chaos testing.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import os
+import statistics
 import time
 from typing import Any, Callable
 
@@ -69,6 +71,13 @@ from repro.train import checkpoint
 from repro.train.elastic import ElasticConfig, PermanentFailure, Supervisor
 from repro.train.state import TrainState
 from repro.utils.retry import retry_call
+
+# stalled-step report: a step whose wall exceeds STALL_FACTOR x the median
+# of the attempt's previous (at most STALL_WINDOW) steps, once there are
+# STALL_MIN_STEPS of them, emits a ``step_stall`` event
+STALL_FACTOR = 2.0
+STALL_WINDOW = 16
+STALL_MIN_STEPS = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,7 +145,8 @@ def make_train_step(loss_fn: Callable, mesh, dp_axes: tuple[str, ...],
         scale = state.loss_scale
 
         def total_loss(p):
-            loss, aux = loss_fn(p, batch, dp_axes)
+            with jax.named_scope("forward"):
+                loss, aux = loss_fn(p, batch, dp_axes)
             tot = loss + cfg.aux_weight * aux
             if guard.enabled:
                 tot = tot * scale.astype(tot.dtype)
@@ -144,42 +154,47 @@ def make_train_step(loss_fn: Callable, mesh, dp_axes: tuple[str, ...],
 
         (_, (loss, aux)), grads = jax.value_and_grad(
             total_loss, has_aux=True)(state.params)
-        grads = sync_tree(grads, grid, cfg.grad_sync)
-        if guard.enabled:
-            inv = 1.0 / scale   # exact for the power-of-two scales we use
-            grads = jax.tree.map(lambda g: g * inv.astype(g.dtype), grads)
-
-        loss_m = jax.lax.pmean(loss, dp_axes)
-        # all-finite flag over loss + synced grads: the all-reduce already
-        # propagated any shard's NaN/Inf to every shard, so the flag (and
-        # the skip decision) is identical across the mesh.
-        nonfinite = sum(
-            jnp.sum(~jnp.isfinite(g.astype(jnp.float32)))
-            for g in jax.tree.leaves(grads))
-        finite = jnp.isfinite(loss_m) & (nonfinite == 0)
+        with jax.named_scope("exchange"):
+            grads = sync_tree(grads, grid, cfg.grad_sync)
+        with jax.named_scope("guard"):
+            if guard.enabled:
+                inv = 1.0 / scale   # exact for the power-of-two scales
+                grads = jax.tree.map(lambda g: g * inv.astype(g.dtype),
+                                     grads)
+            loss_m = jax.lax.pmean(loss, dp_axes)
+            # all-finite flag over loss + synced grads: the all-reduce
+            # already propagated any shard's NaN/Inf to every shard, so the
+            # flag (and the skip decision) is identical across the mesh.
+            nonfinite = sum(
+                jnp.sum(~jnp.isfinite(g.astype(jnp.float32)))
+                for g in jax.tree.leaves(grads))
+            finite = jnp.isfinite(loss_m) & (nonfinite == 0)
 
         lr = schedule.lr(epoch)
         mom = schedule.mom(epoch, global_batch)
-        new_params, new_opt = lars_lib.update(
-            state.params, grads, state.opt_state, lr=lr, momentum=mom,
-            cfg=cfg.lars)
+        with jax.named_scope("lars"):
+            new_params, new_opt = lars_lib.update(
+                state.params, grads, state.opt_state, lr=lr, momentum=mom,
+                cfg=cfg.lars)
 
         if guard.enabled:
-            # skip the update on non-finite steps: params/momentum pass
-            # through unchanged (jnp.where selects bit-exactly on True)
-            sel = functools.partial(jnp.where, finite)
-            new_params = jax.tree.map(sel, new_params, state.params)
-            new_opt = jax.tree.map(sel, new_opt, state.opt_state)
-            good = jnp.where(finite, state.good_steps + 1, 0)
-            grow = finite & (good >= guard.growth_interval)
-            new_scale = jnp.where(
-                finite,
-                jnp.where(grow,
-                          jnp.minimum(scale * guard.growth_factor,
-                                      guard.max_scale),
-                          scale),
-                jnp.maximum(scale * guard.backoff_factor, guard.min_scale))
-            good = jnp.where(grow, 0, good).astype(jnp.int32)
+            with jax.named_scope("guard"):
+                # skip the update on non-finite steps: params/momentum pass
+                # through unchanged (jnp.where selects bit-exactly on True)
+                sel = functools.partial(jnp.where, finite)
+                new_params = jax.tree.map(sel, new_params, state.params)
+                new_opt = jax.tree.map(sel, new_opt, state.opt_state)
+                good = jnp.where(finite, state.good_steps + 1, 0)
+                grow = finite & (good >= guard.growth_interval)
+                new_scale = jnp.where(
+                    finite,
+                    jnp.where(grow,
+                              jnp.minimum(scale * guard.growth_factor,
+                                          guard.max_scale),
+                              scale),
+                    jnp.maximum(scale * guard.backoff_factor,
+                                guard.min_scale))
+                good = jnp.where(grow, 0, good).astype(jnp.int32)
         else:
             new_scale, good = state.loss_scale, state.good_steps
 
@@ -231,7 +246,7 @@ class Trainer:
         at stage ends, and on every skipped step) interleaved with event
         rows (grad-sync downgrades, data retries, checkpoint
         saves/recoveries, resume, ``elastic_failure`` /
-        ``elastic_recovery``). Every row carries a ``"kind"`` marker --
+        ``elastic_recovery``, ``step_stall``). Every row carries a ``"kind"`` marker --
         ``"metric"`` or ``"event"`` -- so a serialized history round-trips
         through JSONL unambiguously; rows are mirrored to the run's
         telemetry sink (``cfg.obs.metrics_path``) with per-step phase
@@ -310,7 +325,7 @@ class Trainer:
             # all-reduces overlapping backward) is captured alongside the
             # host spans (docs/observability.md)
             with jax_profile(cfg.obs.jax_profile_dir
-                             if cfg.obs.enabled else None):
+                             if cfg.obs.enabled else None), tel.active():
                 while True:
                     context = ("startup" if supervisor.recoveries == 0
                                else "elastic")
@@ -372,8 +387,11 @@ class Trainer:
         ``sync_wait`` / ``log`` / ``checkpoint`` children covering its full
         body, so the phase durations account for (nearly all of) the step's
         wall time -- docs/observability.md asserts the sum lands within 10%.
+        A step much slower than the attempt's recent ones emits a
+        ``step_stall`` event with its phases, compiles and GC time.
         """
         reg = tel.registry
+        walls = collections.deque(maxlen=STALL_WINDOW)
         for stage in self.plan.stages:
             gb = stage.global_batch
             if start_step >= stage.first_step + stage.num_steps:
@@ -390,6 +408,7 @@ class Trainer:
                 if failure is not None:
                     raise failure
                 epoch = epoch_of(self.plan, stage, i)
+                tel.take_step_counts()
                 with tel.span("step", step=gstep) as sp_step:
                     with tel.span("data", step=gstep) as sp_data:
                         batch = self._fetch_batch(data_fn, gstep, gb, event)
@@ -454,7 +473,25 @@ class Trainer:
                             self._drain(writer, event)
                 # host-side step accounting (outside the step span so the
                 # recording cost is not inside what it measures)
-                reg.histogram("step/wall_s").observe(sp_step.duration)
+                compiles, gc_s = tel.take_step_counts()
+                phases = {"data": sp_data.duration,
+                          "dispatch": sp_disp.duration,
+                          "sync_wait": sp_sync.duration,
+                          "log": sp_log.duration,
+                          "checkpoint": sp_ckpt.duration}
+                wall = sp_step.duration
+                if len(walls) >= STALL_MIN_STEPS:
+                    median = statistics.median(walls)
+                    if wall > STALL_FACTOR * median:
+                        reg.counter("step/stalls").inc()
+                        event("step_stall", step=done,
+                              wall_s=round(wall, 6),
+                              median_s=round(median, 6),
+                              phases={k: round(v, 6)
+                                      for k, v in phases.items()},
+                              compiles=compiles, gc_s=round(gc_s, 6))
+                walls.append(wall)
+                reg.histogram("step/wall_s").observe(wall)
                 reg.histogram("step/data_s").observe(sp_data.duration)
                 reg.histogram("step/sync_wait_s").observe(sp_sync.duration)
                 reg.counter("train/steps").inc()
@@ -469,12 +506,7 @@ class Trainer:
                         and done % max(1, cfg.obs.step_metrics_every) == 0):
                     tel.emit({
                         "kind": "metric", "metric": "step_phases",
-                        "step": done, "wall_s": sp_step.duration,
-                        "phases": {"data": sp_data.duration,
-                                   "dispatch": sp_disp.duration,
-                                   "sync_wait": sp_sync.duration,
-                                   "log": sp_log.duration,
-                                   "checkpoint": sp_ckpt.duration}})
+                        "step": done, "wall_s": wall, "phases": phases})
             # stage-boundary save, unless the periodic save just covered it
             if self.checkpoint_dir and not (
                     cfg.ckpt_every_steps

@@ -8,6 +8,7 @@ stays under 5% of a step."""
 
 import json
 import os
+import re
 import threading
 import time
 
@@ -18,7 +19,7 @@ from repro.obs import ObsConfig, Telemetry, fingerprint
 from repro.obs.metrics import (DEFAULT_TIME_EDGES_S, MetricsRegistry,
                                NULL_REGISTRY)
 from repro.obs.sink import JsonlSink, read_jsonl, read_run, run_paths
-from repro.obs.tracing import Tracer
+from repro.obs.tracing import ANNOTATION_PREFIX, Tracer
 
 
 # ------------------------------------------------------------- metrics --
@@ -212,8 +213,6 @@ def test_span_nesting_depth_and_parent():
     assert outer.t0 <= inner.t0 and inner.t1 <= outer.t1 + 1e-6
     assert outer.duration >= inner.duration
     assert tr.spans("sync/bucket3", step=3) == [inner]
-    bd = tr.phase_breakdown(3)
-    assert set(bd) == {"step", "sync/bucket3"}
 
 
 def test_span_exception_safety():
@@ -256,6 +255,240 @@ def test_chrome_trace_export_loadable_and_nested(tmp_path):
         assert e["ts"] >= step["ts"]
         assert e["ts"] + e["dur"] <= step["ts"] + step["dur"] + 1.0  # µs
         assert e["args"]["step"] == 0
+
+
+def _profiled(tmp_path, body):
+    """Host events ``(line, name, start_ns, end_ns)`` of a CPU profiler
+    session around ``body()``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True))[-1]
+    return [(line.name, ev.name, ev.start_ns, ev.end_ns)
+            for plane in ProfileData.from_file(path).planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_spans_are_profiler_annotations(tmp_path):
+    """An enabled tracer's spans land in the profiler's trace as
+    ``trainer.<name>``, nested as in the program; a disabled one's do
+    not, and the tracer's own record keeps the plain names."""
+    tr, off = Tracer(), Tracer(enabled=False)
+
+    def body():
+        with tr.span("step", step=0):
+            with tr.span("data", step=0):
+                time.sleep(0.002)
+            with off.span("hidden"):
+                pass
+
+    events = _profiled(tmp_path, body)
+    mine = {name: (line, s, e) for line, name, s, e in events
+            if name.startswith(ANNOTATION_PREFIX)}
+    assert set(mine) == {"trainer.step", "trainer.data"}
+    (l_step, s0, e0), (l_data, s1, e1) = mine["trainer.step"], \
+        mine["trainer.data"]
+    assert l_step == l_data                 # one host line
+    assert s0 <= s1 < e1 <= e0 and e1 - s1 >= 2e6
+    assert [sp.name for sp in tr.spans()] == ["step", "data"]
+
+
+def test_tracer_keeps_newest_spans_and_counts_drops(monkeypatch):
+    from repro.obs import tracing
+
+    monkeypatch.setattr(tracing, "MAX_SPANS", 4)
+    reg = MetricsRegistry()
+    tr = Tracer(metrics=reg)
+    for k in range(6):
+        with tr.span("step", step=k):
+            pass
+    assert [sp.step for sp in tr.spans()] == [2, 3, 4, 5]
+    assert reg.counter("trace/dropped_spans").value == 2
+
+
+# -------------------------------------------- compile and GC accounting --
+
+def _fresh_compile(tag: float):
+    """Compile (and run) a program no other test has compiled."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.jit(lambda x: x * tag + 1.0)(jnp.ones(3)).block_until_ready()
+
+
+def _counts(tel):
+    snap = tel.registry.snapshot()
+    return tuple(snap.get(k, {}).get("value", 0)
+                 for k in ("compile/count", "host/gc_collections"))
+
+
+def test_gc_inside_a_span_is_counted():
+    import gc
+
+    tel = Telemetry()
+    with tel.active(), tel.span("step", step=0):
+        gc.collect()
+    assert _counts(tel)[1] >= 1
+    snap = tel.registry.snapshot()
+    assert snap["host/gc_s"]["count"] == _counts(tel)[1]
+    compiles, gc_s = tel.take_step_counts()
+    assert gc_s > 0 and tel.take_step_counts() == (0, 0.0)
+
+
+def test_telemetries_in_one_process_do_not_double_count():
+    """Each compile and collection feeds the innermost active telemetry
+    once, and no other; the process-wide hooks are registered once."""
+    import gc
+
+    import jax
+
+    from repro import obs
+
+    raw = []                # every backend compile the process reports
+
+    def listener(event, secs, **_):
+        if event == obs.COMPILE_EVENT:
+            raw.append(secs)
+
+    a, b = Telemetry(), Telemetry()
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    gc.disable()            # no automatic collection inside the counts
+    try:
+        with a.active():
+            _fresh_compile(2.5)
+            gc.collect()
+            assert _counts(a) == (len(raw), 1) and len(raw) >= 1
+            assert _counts(b) == (0, 0)
+            with b.active():             # the innermost is fed, alone
+                n = len(raw)
+                _fresh_compile(3.5)
+                gc.collect()
+                assert _counts(b) == (len(raw) - n, 1) and len(raw) > n
+            assert _counts(a) == (n, 1)
+            with a.active():             # re-entered: still counted once
+                _fresh_compile(4.5)
+            assert _counts(a)[0] + _counts(b)[0] == len(raw)
+        seen = len(raw)
+        _fresh_compile(5.5)              # nobody active: nobody counts
+        gc.collect()
+        assert len(raw) > seen
+        assert _counts(a)[0] + _counts(b)[0] == seen
+        assert _counts(a)[1] == 1 and _counts(b)[1] == 1
+    finally:
+        gc.enable()
+        jax.monitoring.unregister_event_duration_listener(listener)
+    for _ in range(3):
+        with Telemetry().active():
+            pass
+    listeners = jax._src.monitoring.get_event_duration_listeners()
+    assert listeners.count(obs._on_duration) == 1
+    assert gc.callbacks.count(obs._on_gc) == 1
+    # a disabled telemetry is never fed
+    off = Telemetry(ObsConfig(enabled=False))
+    with off.active():
+        _fresh_compile(6.5)
+    assert off.take_step_counts() == (0, 0.0)
+
+
+# ------------------------------------------- stalled steps in the trainer --
+
+def _tiny_trainer(n_steps, data_fn):
+    """A 1-device MLP trainer of ``n_steps`` steps of 8 rows."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.batch_control import build_plan
+    from repro.core.grad_sync import GradSyncConfig
+    from repro.core.schedules import BatchSchedule, BatchStage
+    from repro.launch.train import device_mesh
+    from repro.train.state import TrainState
+    from repro.train.trainer import Trainer, TrainerConfig
+
+    def loss_fn(params, batch, dp_axes):
+        x, y = batch
+        return (jnp.mean((jnp.tanh(x @ params["w"]) - y) ** 2),
+                jnp.zeros((), jnp.float32))
+
+    tcfg = TrainerConfig(grad_sync=GradSyncConfig(strategy="psum"),
+                         log_every=1)
+    plan = build_plan(BatchSchedule((BatchStage(0, 1.0, 8),)),
+                      dataset_size=8 * n_steps, n_workers=1,
+                      max_steps=n_steps)
+    trainer = Trainer(mesh=device_mesh(jax.devices()[:1]),
+                      dp_axes=("dy", "dx"), loss_fn=loss_fn, cfg=tcfg,
+                      plan=plan, data_fn=data_fn, telemetry=Telemetry())
+    state = TrainState.create({"w": jnp.eye(16, dtype=jnp.float32)})
+    return trainer, state
+
+
+def _rows(i, gb):
+    rng = np.random.RandomState(i)
+    return (rng.randn(gb, 16).astype(np.float32),
+            rng.randn(gb, 16).astype(np.float32))
+
+
+def test_trainer_reports_a_stalled_step_with_its_phase():
+    """Every fetch sleeps 50 ms, the sixth 0.4 s: exactly that step is
+    reported, with ``data`` its largest phase."""
+    def slow_data(i, gb):
+        time.sleep(0.4 if i == 5 else 0.05)
+        return _rows(i, gb)
+
+    lines = []
+    trainer, state = _tiny_trainer(6, slow_data)
+    _, history = trainer.run(state, log=lines.append)
+    stalls = [h for h in history if h.get("event") == "step_stall"]
+    assert len(stalls) == 1, stalls
+    st = stalls[0]
+    assert st["step"] == 6
+    assert st["wall_s"] > 2 * st["median_s"]
+    assert max(st["phases"], key=st["phases"].get) == "data"
+    assert st["phases"]["data"] >= 0.4
+    assert st["compiles"] == 0 and st["gc_s"] >= 0.0
+    reg = trainer.telemetry.registry
+    assert reg.counter("step/stalls").value == 1
+    assert any(line.startswith("[step_stall] step=6") for line in lines)
+
+
+def test_compile_count_flat_after_the_first_step():
+    """The first step compiles; later steps of the same shape do not."""
+    counts = []             # compile/count at each step's log line
+    trainer, state = _tiny_trainer(4, _rows)
+    reg = trainer.telemetry.registry
+    trainer.run(state, log=lambda msg: counts.append(
+        reg.counter("compile/count").value))
+    assert len(counts) == 4
+    assert counts[0] >= 1
+    assert counts[1:] == [counts[0]] * 3
+
+
+def test_step_hlo_carries_named_scopes():
+    """The step's lowered HLO names its four parts in ``op_name``
+    metadata; the backward ops carry ``transpose(jvp(forward))``."""
+    import jax
+    import jax.numpy as jnp
+    from repro.train.trainer import make_train_step
+
+    trainer, state = _tiny_trainer(1, _rows)
+    fn = make_train_step(trainer.loss_fn, trainer.mesh, trainer.dp_axes,
+                         trainer.cfg, donate=False)
+    hlo = fn.lower(jax.device_put(state), _rows(0, 8),
+                   jnp.asarray(0.0, jnp.float32),
+                   jnp.asarray(8.0, jnp.float32)).as_text(
+                       dialect="hlo", debug_info=True)
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in ("forward", "exchange", "guard", "lars"):
+        # the forward pass is differentiated: ``jvp(forward)``
+        assert any(re.search(rf"[/(]{scope}[)/]", n) for n in names), scope
+    assert any("transpose(jvp(forward))" in n for n in names)
 
 
 # -------------------------------------------------- fingerprint/telemetry --

@@ -54,13 +54,14 @@ def test_resnet_paper_recipe_converges(mesh):
     state = TrainState.create(resnet.init(jax.random.key(0), cfg))
     state, history = trainer.run(state, log=lambda *a: None)
 
-    assert len(history) > 0
-    losses_seen = [h["loss"] for h in history]
+    steps = [h for h in history if h["kind"] == "metric"]
+    assert len(steps) > 0
+    losses_seen = [h["loss"] for h in steps]
     assert all(np.isfinite(l) for l in losses_seen)
     # learnable synthetic data: loss must drop from the first record
     assert losses_seen[-1] < losses_seen[0], losses_seen
     # batch-size control actually switched stages
-    gbs = {h["global_batch"] for h in history}
+    gbs = {h["global_batch"] for h in steps}
     assert gbs == {16, 32}
     assert int(state.step) == 32
 
@@ -138,7 +139,8 @@ def test_transformer_lm_trains_with_recipe(mesh):
                       data_fn=lambda i, gb: data.batch(i, gb, 32))
     state = TrainState.create(T.init(jax.random.key(2), cfg))
     state, history = trainer.run(state, log=lambda *a: None)
-    assert history[-1]["loss"] < history[0]["loss"]
+    steps = [h for h in history if h["kind"] == "metric"]
+    assert steps[-1]["loss"] < steps[0]["loss"]
 
 
 def test_checkpoint_roundtrip(tmp_path):
