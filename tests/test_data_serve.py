@@ -1,5 +1,7 @@
 """Data pipeline (synthetic + augmentations) and serving-path tests."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,6 +83,132 @@ def test_augment_property_bounded_output(seed):
     imgs = jnp.clip(jax.random.normal(jax.random.key(seed), (2, 16, 16, 3)), -3, 3)
     out = augment.augment(jax.random.key(seed + 1), imgs, out_hw=(16, 16))
     assert np.abs(np.asarray(out)).max() < 50
+
+
+def _four_gather_resample(images, mats, out_hw):
+    """The resample as four per-corner gathers of a C-wide row: the plain
+    reference for ``augment._affine_resample``."""
+    B, H, W, C = images.shape
+    oh, ow = out_hw
+    ys, xs = jnp.meshgrid(jnp.arange(oh, dtype=jnp.float32),
+                          jnp.arange(ow, dtype=jnp.float32), indexing="ij")
+    grid = jnp.stack([ys.ravel(), xs.ravel(), jnp.ones(oh * ow)], 0)
+    src = jnp.einsum("bij,jp->bip", mats, grid)
+    sy, sx = src[:, 0], src[:, 1]
+    y0 = jnp.floor(sy)
+    x0 = jnp.floor(sx)
+    wy = sy - y0
+    wx = sx - x0
+
+    def gather(yi, xi):
+        yc = jnp.clip(yi.astype(jnp.int32), 0, H - 1)
+        xc = jnp.clip(xi.astype(jnp.int32), 0, W - 1)
+        flat = images.reshape(B, H * W, C)
+        return jnp.take_along_axis(flat, (yc * W + xc)[..., None], axis=1)
+
+    out = (gather(y0, x0) * ((1 - wy) * (1 - wx))[..., None]
+           + gather(y0, x0 + 1) * ((1 - wy) * wx)[..., None]
+           + gather(y0 + 1, x0) * (wy * (1 - wx))[..., None]
+           + gather(y0 + 1, x0 + 1) * (wy * wx)[..., None])
+    return out.reshape(B, oh, ow, C)
+
+
+def _affine(hw, out_hw, rot=0.0, scale=1.0, shift=(0.0, 0.0)):
+    """One (2, 3) map as ``random_affine`` builds it: rotate by ``rot``
+    degrees and scale about the centres, then shift by ``shift`` pixels."""
+    (H, W), (oh, ow) = hw, out_hw
+    a = np.deg2rad(rot)
+    cos, sin = np.cos(a) / scale, np.sin(a) / scale
+    cy, cx, ocy, ocx = (H - 1) / 2, (W - 1) / 2, (oh - 1) / 2, (ow - 1) / 2
+    return np.array([[cos, -sin, cy - cos * ocy + sin * ocx + shift[0]],
+                     [sin, cos, cx - sin * ocy - cos * ocx + shift[1]]],
+                    np.float32)
+
+
+# (input H, W), out_hw, one map per image of the batch
+_RESAMPLE_CASES = {
+    "square": ((16, 16), (16, 16), [_affine((16, 16), (16, 16), 10, 1.1),
+                                    _affine((16, 16), (16, 16), -7, 0.8,
+                                            (1.3, -2.6))]),
+    "non_square": ((12, 20), (12, 20), [_affine((12, 20), (12, 20), 12, 0.9),
+                                        _affine((12, 20), (12, 20), -3, 1.2,
+                                                (0.5, 2.25))]),
+    "resize": ((20, 14), (9, 23), [_affine((20, 14), (9, 23), 5, 1.0),
+                                   _affine((20, 14), (9, 23), -15, 1.3)]),
+    "integer_sources": ((10, 13), (10, 13), [_affine((10, 13), (10, 13)),
+                                             _affine((10, 13), (10, 13),
+                                                     shift=(3.0, -2.0))]),
+    # rows and columns -1, 0, ..., H-1, H (and W likewise) exactly, with
+    # and without a fractional part
+    "edge_sources": ((7, 9), (9, 11), [
+        np.array([[1, 0, -1], [0, 1, -1]], np.float32),
+        np.array([[1, 0, -1.25], [0, 1, -0.75]], np.float32)]),
+    "far_outside": ((16, 12), (16, 12), [
+        _affine((16, 12), (16, 12), 15, 0.7, (200.0, -150.0)),
+        _affine((16, 12), (16, 12), -15, 1.3, (-90.0, 75.5))]),
+    "far_outside_negative": ((16, 12), (16, 12), [
+        _affine((16, 12), (16, 12), -15, 0.7, (-300.0, -40.0)),
+        _affine((16, 12), (16, 12), 15, 1.3, (41.0, 500.0))]),
+}
+
+
+@pytest.mark.parametrize("per_gather", ["batch", "image"])
+@pytest.mark.parametrize("case", sorted(_RESAMPLE_CASES))
+def test_resample_matches_four_gather_reference(case, per_gather,
+                                                 monkeypatch):
+    """The 2x2-table resample gives, bit for bit, what gathering each
+    corner on its own gives: the same clamped corners, weights and sum,
+    whether one gather reads the whole batch's table or one image's."""
+    if per_gather == "image":
+        monkeypatch.setattr(augment, "GATHER_TABLE_BYTES", 0)
+    hw, out_hw, mats = _RESAMPLE_CASES[case]
+    imgs = jax.random.normal(jax.random.key(5), (len(mats), *hw, 3))
+    mats = jnp.asarray(np.stack(mats))
+    want = jax.jit(_four_gather_resample, static_argnums=2)(imgs, mats, out_hw)
+    got = jax.jit(augment._affine_resample, static_argnums=2)(imgs, mats,
+                                                              out_hw)
+    assert got.shape == (len(mats), *out_hw, 3)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_resample_in_groups_of_images_matches_reference(monkeypatch):
+    """Six images gathered two at a time, each with a map of its own."""
+    hw = (11, 14)
+    per_image = (hw[0] + 1) * (hw[1] + 1) * 4 * 3 * 4
+    monkeypatch.setattr(augment, "GATHER_TABLE_BYTES", 2 * per_image)
+    assert augment.images_per_gather((6, *hw, 3)) == 2
+    mats = np.stack([_affine(hw, hw, r, s, (d, -d)) for r, s, d in
+                     [(-15, 0.7, 0), (-9, 0.9, 1.5), (-3, 1.1, -40),
+                      (3, 1.3, 2.25), (9, 1.0, 0.5), (15, 0.8, 30)]])
+    imgs = jax.random.normal(jax.random.key(6), (6, *hw, 3))
+    want = jax.jit(_four_gather_resample, static_argnums=2)(imgs, mats, hw)
+    got = jax.jit(augment._affine_resample, static_argnums=2)(imgs, mats, hw)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("shape,want", [((256, 224, 224, 3), 16),
+                                        ((128, 224, 224, 3), 16),
+                                        ((24, 224, 224, 3), 12),
+                                        ((97, 224, 224, 3), 1),
+                                        ((2, 16, 16, 3), 2),
+                                        ((1, 4000, 4000, 3), 1)])
+def test_images_per_gather(shape, want):
+    """The largest divisor of the batch whose table fits the budget, and
+    never fewer than one image."""
+    assert augment.images_per_gather(shape) == want
+
+
+def test_augment_makes_one_gather_of_four_corners():
+    """The compiled augmentation gathers once, a 2x2 neighbourhood (4C
+    values) per output pixel, not once per corner."""
+    C = 3
+    hlo = jax.jit(augment.augment, static_argnums=2).lower(
+        jax.random.key(0), jnp.zeros((2, 16, 16, C)), (16, 16)
+    ).compile().as_text()
+    gathers = [line for line in hlo.splitlines() if " gather(" in line]
+    assert len(gathers) == 1, gathers
+    sizes = re.search(r"slice_sizes=\{([\d,]+)\}", gathers[0]).group(1)
+    assert np.prod([int(v) for v in sizes.split(",")]) == 4 * C
 
 
 # ---------------------------------------------------------------- batcher --

@@ -12,6 +12,8 @@ module is imported: only one process at a time may load libtpu, and every
 xdist worker imports every test file. Keep all such tests in this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,7 +26,8 @@ from repro.core.grad_sync import GradSyncConfig, sync_tree
 from repro.core.topology import TorusGrid
 from repro.kernels import ops
 from repro.launch import hlo_stats
-from repro.launch.train import device_mesh, resnet_job
+from repro.data import augment
+from repro.launch.train import _augment_batch, device_mesh, resnet_job
 from repro.models import resnet
 from repro.train.trainer import make_train_step
 
@@ -109,6 +112,27 @@ def test_flash_attention_compiles(one_chip):
         return ops.flash_attention(q, k, v, interpret=False)
 
     assert "tpu_custom_call" in _compiled_text(attn, q, kv, kv)
+
+
+# ------------------------------------------------------------- input --
+
+@pytest.mark.parametrize("batch", [256, 128])
+def test_augment_gathers_in_groups_from_vmem(one_chip, batch):
+    """The ResNet job's augmentation at 224 px, as the one-chip cell (256
+    images) and the four-chip cell's chip 0 (global 128) run it: one
+    gather, of a 12-float neighbourhood row, whose operand is one group's
+    table placed in on-chip memory (memory space ``S(1)``)."""
+    images = _sds((batch, 224, 224, 3), jnp.float32, one_chip)
+    key = _sds((), jax.random.key(0).dtype, one_chip)
+    hlo = _augment_batch.lower(key, images).compile().as_text()
+    gathers = [ln for ln in hlo.splitlines() if " gather(" in ln]
+    assert len(gathers) == 1, gathers
+    assert "slice_sizes={1,1,12}" in gathers[0], gathers[0]
+    operand = re.search(r" gather\(%([\w.-]+),", gathers[0]).group(1)
+    (decl,) = [ln for ln in hlo.splitlines()
+               if ln.strip().startswith(f"%{operand} = ")]
+    k = augment.images_per_gather((batch, 224, 224, 3))
+    assert f"f32[{k},50625,12]" in decl and "S(1)" in decl, decl
 
 
 # ------------------------------------------------------ gradient exchange --
