@@ -11,8 +11,11 @@ neighbourhood -- one indexed pass for the geometric group.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def random_flip(key, images):
@@ -102,9 +105,14 @@ def _affine_resample(images, mats, out_hw):
 
 
 def random_affine(key, images, out_hw=None, max_rot=15.0, scale=(0.7, 1.3),
-                  max_shift=0.1):
+                  max_shift=0.1, mesh=None):
     """Rotation + scale + shift ('rotations, scaling, distortion, resizing')
-    in one bilinear resample."""
+    in one bilinear resample.
+
+    With a ``mesh``, the batch's rows are split over all of its axes and
+    each device resamples its own rows (``shard_map``): the grouped gather
+    loops over the batch, and a loop over a split axis is not split by the
+    partitioner. The maps are drawn for the whole batch either way."""
     B, H, W, _ = images.shape
     oh, ow = out_hw or (H, W)
     k1, k2, k3 = jax.random.split(key, 3)
@@ -121,14 +129,19 @@ def random_affine(key, images, out_hw=None, max_rot=15.0, scale=(0.7, 1.3),
         jnp.stack([cos, -sin, cy - cos * ocy + sin * ocx + shift[:, 0]], 1),
         jnp.stack([sin, cos, cx - sin * ocy - cos * ocx + shift[:, 1]], 1),
     ], 1)                                                              # (B,2,3)
-    return _affine_resample(images, m, (oh, ow))
+    resample = functools.partial(_affine_resample, out_hw=(oh, ow))
+    if mesh is not None:
+        rows = P(tuple(mesh.axis_names))
+        resample = jax.shard_map(resample, mesh=mesh, in_specs=(rows, rows),
+                                 out_specs=rows)
+    return resample(images, m)
 
 
-def augment(key, images, out_hw=(224, 224)):
+def augment(key, images, out_hw=(224, 224), mesh=None):
     """The paper's full augmentation stack, fused order: geometric ->
-    flip -> photometric -> noise."""
+    flip -> photometric -> noise. ``mesh``: see ``random_affine``."""
     k = jax.random.split(key, 5)
-    x = random_affine(k[0], images, out_hw)
+    x = random_affine(k[0], images, out_hw, mesh=mesh)
     x = random_flip(k[1], x)
     x = random_brightness(k[2], x)
     x = random_contrast(k[3], x)

@@ -19,11 +19,13 @@ and ``chip_smoke.py`` run it.
 from __future__ import annotations
 
 import argparse
+import functools
 import math
 import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import registry
 from repro.core import losses
@@ -68,10 +70,33 @@ def device_mesh(devices=None) -> jax.sharding.Mesh:
                          devices=devices)
 
 
-@jax.jit
-def _augment_batch(key, images):
-    """Paper §3.2 augmentation pipeline, on the device."""
-    return augment.augment(key, images, out_hw=images.shape[1:3])
+def image_batches(data: SyntheticImageNet, mesh):
+    """``data_fn(index, global_batch)`` of synthetic ImageNet, augmented
+    as in paper §3.2: one jitted program, ``index`` below 2**31.
+
+    The program's images and labels come out split by rows over every axis
+    of ``mesh``, as the train step takes its batch (``P(dp_axes)``), and
+    each device makes only its own rows: device ``c`` of ``n`` holds rows
+    ``[c*gb/n, (c+1)*gb/n)``. Every random value is drawn at the global
+    batch's shape, so a row's values do not depend on the mesh; threefry
+    being partitionable (JAX's default), each device draws only its rows'
+    bits. The program holds no collective: no device waits on another for
+    its rows, and the step reshards nothing.
+    """
+    axes = tuple(mesh.axis_names)
+    auto = Mesh(mesh.devices, axes)     # constraints need Auto axes
+    rows = NamedSharding(auto, P(axes))
+
+    @functools.partial(jax.jit, static_argnums=1,
+                       out_shardings=NamedSharding(mesh, P(axes)))
+    def image_batch(index, gb):
+        images, labels = jax.lax.with_sharding_constraint(
+            data.batch(index, gb), rows)
+        key = jax.random.fold_in(jax.random.key(0), index)
+        return (augment.augment(key, images, out_hw=images.shape[1:3],
+                                mesh=auto), labels)
+
+    return image_batch
 
 
 def resnet_job(cfg: resnet.ResNetConfig, *, per_chip_batches=(256,),
@@ -83,20 +108,17 @@ def resnet_job(cfg: resnet.ResNetConfig, *, per_chip_batches=(256,),
     ``steps_per_stage`` steps long, counted in epochs of ImageNet-1k so the
     LR and momentum schedules (config B) see the start of a real run.
     Synthetic ImageNet at ``cfg.image_size`` with ``cfg.num_classes``
-    classes, augmented on the device; label smoothing 0.1, LARS, and a
+    classes, made and augmented by each device for its own rows
+    (``image_batches``); label smoothing 0.1, LARS, and a
     bf16 gradient exchange with ``strategy``. Images, labels and initial
     weights are all made from seed 0, so two jobs see the same ones.
     """
     mesh = device_mesh() if mesh is None else mesh
     dp_axes = tuple(mesh.axis_names)
     n_workers = mesh.devices.size
-    data = SyntheticImageNet(num_classes=cfg.num_classes,
-                             image_size=cfg.image_size)
-
-    def data_fn(i, gb):
-        images, labels = data.batch(i, gb)
-        key = jax.random.fold_in(jax.random.key(0), i)
-        return _augment_batch(key, images), labels
+    data_fn = image_batches(SyntheticImageNet(num_classes=cfg.num_classes,
+                                              image_size=cfg.image_size),
+                            mesh)
 
     def loss_fn(params, batch, dp_axes):
         images, labels = batch

@@ -224,6 +224,19 @@ def make_train_step(loss_fn: Callable, mesh, dp_axes: tuple[str, ...],
     return jax.jit(smapped, donate_argnums=(0,) if donate else ())
 
 
+def batch_layout(batch, rows: NamedSharding) -> tuple[int, bool]:
+    """``(devices, resharded)`` of a batch, from host metadata alone: the
+    number of devices its first leaf lives on (0 for a host array), and
+    whether any leaf is not laid out as ``rows``, the step's split of the
+    batch, so that the step's dispatch must move it first."""
+    leaves = jax.tree.leaves(batch)
+    shardings = [getattr(x, "sharding", None) for x in leaves]
+    devices = 0 if shardings[0] is None else len(shardings[0].device_set)
+    resharded = any(s is None or not s.is_equivalent_to(rows, x.ndim)
+                    for s, x in zip(shardings, leaves))
+    return devices, resharded
+
+
 @dataclasses.dataclass
 class Trainer:
     mesh: Any
@@ -392,6 +405,7 @@ class Trainer:
         """
         reg = tel.registry
         walls = collections.deque(maxlen=STALL_WINDOW)
+        rows = NamedSharding(self.mesh, P(self.dp_axes))
         for stage in self.plan.stages:
             gb = stage.global_batch
             if start_step >= stage.first_step + stage.num_steps:
@@ -495,6 +509,10 @@ class Trainer:
                 reg.histogram("step/data_s").observe(sp_data.duration)
                 reg.histogram("step/sync_wait_s").observe(sp_sync.duration)
                 reg.counter("train/steps").inc()
+                devices, resharded = batch_layout(batch, rows)
+                reg.gauge("data/batch_devices").set(devices)
+                if resharded:
+                    reg.counter("data/resharded_batches").inc()
                 if cfg.guard.enabled:
                     if skipped:
                         reg.counter("train/skipped_steps").inc()

@@ -211,6 +211,61 @@ def test_augment_makes_one_gather_of_four_corners():
     assert np.prod([int(v) for v in sizes.split(",")]) == 4 * C
 
 
+# ------------------------------------------------------ the job's batches --
+
+def _parent_batch(data, index, gb):
+    """The ResNet job's batch as one formula on one device: synthetic
+    batch ``index``, then the augmentation keyed by the same index."""
+    images, labels = data.batch(index, gb)
+    key = jax.random.fold_in(jax.random.key(0), index)
+    return augment.augment(key, images, out_hw=images.shape[1:3]), labels
+
+
+@pytest.mark.multidevice
+@pytest.mark.parametrize("n_devices", [1, 4], ids=["1x1", "2x2"])
+def test_resnet_batch_is_laid_out_as_the_step_takes_it(n_devices):
+    """``resnet_job``'s ``data_fn`` returns images and labels split by rows
+    over the mesh exactly as the step splits its batch, each device holding
+    ``gb / n`` rows, and the rows are the one-device formula's global rows
+    bit for bit: the layout changes no value."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.launch.train import device_mesh, resnet_job
+    from repro.models import resnet
+
+    cfg = resnet.ResNetConfig.tiny(image_size=16)
+    mesh = device_mesh(jax.devices()[:n_devices])
+    trainer, _, data_fn = resnet_job(cfg, per_chip_batches=(2,), mesh=mesh)
+    gb, index = 8, 2_000_000_123          # as large as a seed's offset
+    batch = data_fn(index, gb)
+
+    rows = NamedSharding(mesh, P(trainer.dp_axes))
+    for x in batch:
+        assert x.sharding.is_equivalent_to(rows, x.ndim), x.sharding
+        assert len(x.sharding.device_set) == n_devices
+        assert [s.data.shape[0] for s in x.addressable_shards] == \
+            [gb // n_devices] * n_devices
+    data = SyntheticImageNet(num_classes=cfg.num_classes,
+                             image_size=cfg.image_size)
+    want = jax.jit(_parent_batch, static_argnums=(0, 2))(data, index, gb)
+    for got, ref in zip(batch, want):
+        assert np.array_equal(np.asarray(got), np.asarray(ref))
+    # against the synthetic batch made op by op: the labels bitwise; the
+    # images within 8 ulps of the batch's largest value. XLA's CPU backend
+    # computes the compiled ``up + noise * normal`` as one fused
+    # multiply-add where op-by-op execution rounds twice: 1 ulp per pixel,
+    # which the augmentation's mixes and scales carry on (at most 3 ulps
+    # over ten batches); a wrong row or key differs by the data's scale
+    images, labels = data.batch(index, gb)
+    key = jax.random.fold_in(jax.random.key(0), index)
+    eager = jax.jit(augment.augment, static_argnums=2)(key, images,
+                                                       images.shape[1:3])
+    assert np.array_equal(np.asarray(batch[1]), np.asarray(labels))
+    top = np.abs(np.asarray(eager)).max()
+    np.testing.assert_allclose(np.asarray(batch[0]), np.asarray(eager),
+                               rtol=0, atol=8 * np.spacing(top))
+
+
 # ---------------------------------------------------------------- batcher --
 
 def test_batcher_left_pad_and_truncate():

@@ -470,6 +470,32 @@ def test_compile_count_flat_after_the_first_step():
     assert counts[1:] == [counts[0]] * 3
 
 
+@pytest.mark.multidevice
+def test_trainer_reads_where_the_batch_lives():
+    """``data/batch_devices`` and ``data/resharded_batches``: the ResNet
+    job's batch program on four devices makes each batch where the step
+    takes it (4 devices, no reshard); the same batches placed on one device
+    read 1 and one reshard per step."""
+    import jax
+    from repro.launch.train import device_mesh, resnet_job
+    from repro.models import resnet
+
+    trainer, state, data_fn = resnet_job(
+        resnet.ResNetConfig.tiny(image_size=16), per_chip_batches=(2,),
+        steps_per_stage=2, mesh=device_mesh(jax.devices()[:4]))
+
+    def on_one_device(i, gb):       # uncommitted, as eager ops leave it
+        return jax.device_put(jax.device_get(data_fn(i, gb)))
+
+    for fn, devices, resharded in [(data_fn, 4, 0), (on_one_device, 1, 2)]:
+        trainer.data_fn, trainer.telemetry = fn, Telemetry()
+        state, _ = trainer.run(state, log=lambda *a: None)
+        reg = trainer.telemetry.registry
+        assert reg.counter("train/steps").value == 2
+        assert reg.gauge("data/batch_devices").value == devices
+        assert reg.counter("data/resharded_batches").value == resharded
+
+
 def test_step_hlo_carries_named_scopes():
     """The step's lowered HLO names its four parts in ``op_name``
     metadata; the backward ops carry ``transpose(jvp(forward))``."""

@@ -27,7 +27,8 @@ from repro.core.topology import TorusGrid
 from repro.kernels import ops
 from repro.launch import hlo_stats
 from repro.data import augment
-from repro.launch.train import _augment_batch, device_mesh, resnet_job
+from repro.data.synthetic import SyntheticImageNet
+from repro.launch.train import device_mesh, image_batches, resnet_job
 from repro.models import resnet
 from repro.train.trainer import make_train_step
 
@@ -116,23 +117,49 @@ def test_flash_attention_compiles(one_chip):
 
 # ------------------------------------------------------------- input --
 
-@pytest.mark.parametrize("batch", [256, 128])
+def _table_gather(hlo: str) -> str:
+    """The declaration of the operand of the program's one gather of a
+    12-float neighbourhood row (the resample's)."""
+    gathers = [ln for ln in hlo.splitlines()
+               if " gather(" in ln and "slice_sizes={1,1,12}" in ln]
+    assert len(gathers) == 1, gathers
+    operand = re.search(r" gather\(%([\w.-]+),", gathers[0]).group(1)
+    (decl,) = [ln for ln in hlo.splitlines()
+               if ln.strip().startswith(f"%{operand} = ")]
+    return decl
+
+
+@pytest.mark.parametrize("batch", [256, 128, 32])
 def test_augment_gathers_in_groups_from_vmem(one_chip, batch):
-    """The ResNet job's augmentation at 224 px, as the one-chip cell (256
-    images) and the four-chip cell's chip 0 (global 128) run it: one
+    """The augmentation at 224 px on one chip: of 256 images, as the
+    one-chip cell runs it; of 32, as each chip of the four-chip cell runs
+    its rows; of 128, a global batch no cell's chip makes any more. One
     gather, of a 12-float neighbourhood row, whose operand is one group's
     table placed in on-chip memory (memory space ``S(1)``)."""
     images = _sds((batch, 224, 224, 3), jnp.float32, one_chip)
     key = _sds((), jax.random.key(0).dtype, one_chip)
-    hlo = _augment_batch.lower(key, images).compile().as_text()
-    gathers = [ln for ln in hlo.splitlines() if " gather(" in ln]
-    assert len(gathers) == 1, gathers
-    assert "slice_sizes={1,1,12}" in gathers[0], gathers[0]
-    operand = re.search(r" gather\(%([\w.-]+),", gathers[0]).group(1)
-    (decl,) = [ln for ln in hlo.splitlines()
-               if ln.strip().startswith(f"%{operand} = ")]
+    hlo = jax.jit(augment.augment, static_argnums=2).lower(
+        key, images, (224, 224)).compile().as_text()
+    assert len([ln for ln in hlo.splitlines() if " gather(" in ln]) == 1
+    decl = _table_gather(hlo)
     k = augment.images_per_gather((batch, 224, 224, 3))
     assert f"f32[{k},50625,12]" in decl and "S(1)" in decl, decl
+
+
+def test_resnet_batch_program_is_per_chip_on_2x2(topo):
+    """The ResNet job's batch program for the four-chip cell, 32 images of
+    224 px per chip: no collective, no array of the global batch's 128
+    images (nor its table), and each chip's resample gathers 16 images'
+    table at a time from on-chip memory."""
+    mesh = device_mesh(topo.devices)
+    make = image_batches(SyntheticImageNet(), mesh)
+    hlo = make.lower(0, 4 * 32).compile().as_text()
+    counts = hlo_stats.collective_counts(hlo)
+    assert not any(counts.values()), counts
+    globals_ = re.findall(r"f32\[(?:128|8,16),[\d,]*\]", hlo)
+    assert not globals_, sorted(set(globals_))
+    decl = _table_gather(hlo)
+    assert "f32[16,50625,12]" in decl and "S(1)" in decl, decl
 
 
 # ------------------------------------------------------ gradient exchange --
