@@ -13,7 +13,6 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
-import numpy as np
 
 from repro.configs import registry
 from repro.models import transformer as T

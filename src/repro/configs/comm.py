@@ -5,7 +5,7 @@ fabric -- link bandwidth, per-step latency, and the backward-pass wall
 time it overlaps with. This module is the single place those constants
 live for the production meshes (``launch.mesh.make_production_mesh``), so
 ``launch.dryrun`` and the trainer resolve ``bucket_bytes="auto"`` against
-the same numbers the roofline and ``benchmarks/allreduce.py`` use.
+the same numbers.
 
 The per-arch default is simply ``"auto"`` for every arch that runs the
 manual grad sync: the point of the autotuner is that no arch should carry

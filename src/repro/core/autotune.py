@@ -207,10 +207,9 @@ def refine_from_sweep(rows: list[dict], strategy: str, x: int, y: int,
                       slack: float = 0.05) -> dict:
     """Combine sweep artifacts with the analytic model into a final pick.
 
-    ``rows`` come from ``launch/dryrun.py --sweep-bucket-bytes`` (or
-    ``benchmarks/allreduce.py``): each has ``bucket_bytes`` plus whichever
-    evidence the sweep produced -- ``exposed_seconds`` (cost model),
-    ``num_exchanges`` (HLO audit), ``us_per_call`` (measured). The pick is
+    ``rows`` come from ``launch/dryrun.py --sweep-bucket-bytes``: each has
+    ``bucket_bytes`` plus whichever evidence the sweep produced --
+    ``exposed_seconds`` (cost model), ``num_exchanges`` (HLO audit). The pick is
     the sweep row with the fewest exchanges within ``slack`` of the best
     exposed time; the analytic recommendation rides along with an
     ``agrees`` flag (pick inside the sweep's optimum bracket), so a

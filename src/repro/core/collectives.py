@@ -202,7 +202,7 @@ def all_reduce(x: jax.Array, grid: TorusGrid, strategy: str = "torus2d",
 
 
 # ---------------------------------------------------------------------------
-# Analytic cost model (paper §2.2 step counts; used by benchmarks/allreduce)
+# Analytic cost model (paper §2.2 step counts; used by the bucket autotuner)
 # ---------------------------------------------------------------------------
 
 def comm_cost_model(strategy: str, nbytes: int, x: int, y: int,

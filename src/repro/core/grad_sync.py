@@ -50,7 +50,7 @@ axes are manual.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Sequence
+from typing import Any, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -125,8 +125,8 @@ def _ring_multiple(grid: TorusGrid) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Bucket partitioning (pure python; also used by benchmarks and the dry-run
-# HLO audit, so it must stay trace-free)
+# The exchange plan (pure python; the dry-run HLO audit and the bucket gauges
+# read it without a trace, so it must stay trace-free)
 # ---------------------------------------------------------------------------
 
 def partition_buckets(leaf_bytes: Sequence[int], bucket_bytes: int) -> list[list[int]]:
@@ -165,67 +165,63 @@ def _itemsize(dtype) -> int:
     return np.dtype(dtype).itemsize
 
 
-def _precision_groups(leaves_p, cfg: GradSyncConfig) -> list[tuple[list[int], Any]]:
-    """Split leaf indices into (comm-dtype, fp32) groups, preserving order.
-
-    The paper exchanges the bulk of the gradient in half precision but keeps
-    BN statistics / scales / biases (and any fp32 vector leaf) in fp32;
-    buckets never mix the two groups.
-    """
-    comm_idx, fp32_idx = [], []
-    for k, (path, leaf) in enumerate(leaves_p):
-        ps = _path_str(path)
-        if any(tag in ps for tag in cfg.fp32_paths) or \
-                leaf.dtype == jnp.float32 and leaf.ndim <= 1:
-            fp32_idx.append(k)
-        else:
-            comm_idx.append(k)
-    return [(comm_idx, cfg.comm_dtype), (fp32_idx, jnp.float32)]
+class _Exchange(NamedTuple):
+    mode: str                # "fused" | "per_leaf" | "grouped"
+    group: str               # "comm" | "fp32"
+    dtype: Any
+    leaves: list[int]        # leaf indices, in buffer order
 
 
-def _per_leaf_dtype(path, leaf, cfg: GradSyncConfig):
-    """Comm dtype of one leaf on the ``fuse=False`` path (tag match only --
-    mirrors the historical per-leaf classification, which unlike
-    ``_precision_groups`` does not special-case fp32 vectors)."""
-    fp32 = any(tag in _path_str(path) for tag in cfg.fp32_paths)
-    return ("fp32", jnp.float32) if fp32 else ("comm", cfg.comm_dtype)
+def _plan(leaves_p, cfg: GradSyncConfig) -> list[_Exchange]:
+    """The exchanges ``sync_tree`` issues, in issue order.
 
+    ``fuse=True``: the leaves split into a comm-dtype and an fp32 group
+    (the paper exchanges the bulk in half precision but keeps BN
+    statistics / scales / biases, and any fp32 vector leaf, in fp32), the
+    comm group issued first; each group is partitioned by
+    ``partition_buckets`` into ``"fused"`` buffers, one strategy
+    all-reduce each.
 
-def _per_leaf_plan(leaves_p, cfg: GradSyncConfig):
-    """Exchange plan for the ``fuse=False`` path.
-
-    Returns ``(large, groups)``: ``large`` is ``[(leaf_idx, dtype), ...]``
-    in issue order (reverse-backprop when ``cfg.reverse_order``) -- one
-    strategy exchange each, preserving any model-axis sharding on trailing
-    dims. ``groups`` is ``[{"group", "dtype", "buckets": [[leaf_idx...]]}]``
-    -- small leaves (below ``small_leaf_threshold`` elements, or scalars)
-    grouped by precision group, partitioned by ``partition_buckets``
-    (single shared bucket when ``bucket_bytes <= 0``), one ``psum`` per
-    bucket. Grouping ravels leaves, so it relies on small leaves being
+    ``fuse=False``: the groups follow the path tags alone (fp32 vectors
+    are not special-cased). Each large leaf is one ``"per_leaf"`` strategy
+    exchange along its leading dim, preserving any model-axis sharding on
+    trailing dims. Small leaves (below ``small_leaf_threshold`` elements,
+    or scalars) are pooled per group, the pools in order of first
+    appearance, and partitioned into ``"grouped"`` buffers, one ``psum``
+    each. Pooling ravels leaves, so it relies on small leaves being
     replicated over non-grid axes -- which the sharding rules guarantee
     (1-D scales/biases/BN stats are never model-sharded).
+
+    Within each kind, leaves go in reverse-backprop order when
+    ``cfg.reverse_order``: tree-flatten order tracks the forward pass, so
+    the last leaves' grads materialize first in backward -- their exchange
+    is issued first and overlaps the rest of backprop.
     """
     bucket_bytes = _require_resolved(cfg.bucket_bytes)
-    large: list[tuple[int, Any]] = []
-    small: dict[tuple[str, Any], list[int]] = {}
+    dtypes = {"comm": cfg.comm_dtype, "fp32": jnp.float32}
+    singles: list[tuple[str, int]] = []
+    pools: dict[str, list[int]] = {"comm": [], "fp32": []} if cfg.fuse else {}
     for k, (path, leaf) in enumerate(leaves_p):
-        name, dtype = _per_leaf_dtype(path, leaf, cfg)
-        if leaf.size < cfg.small_leaf_threshold or leaf.ndim == 0:
-            small.setdefault((name, dtype), []).append(k)
+        fp32 = any(tag in _path_str(path) for tag in cfg.fp32_paths)
+        if cfg.fuse:
+            # only the fused rule keeps untagged fp32 vectors in fp32; one
+            # rule for both would change the per-leaf path's numerics
+            fp32 = fp32 or leaf.dtype == jnp.float32 and leaf.ndim <= 1
+        group = "fp32" if fp32 else "comm"
+        if cfg.fuse or leaf.size < cfg.small_leaf_threshold or leaf.ndim == 0:
+            pools.setdefault(group, []).append(k)
         else:
-            large.append((k, dtype))
+            singles.append((group, k))
     if cfg.reverse_order:
-        large.reverse()
-    groups = []
-    for (name, dtype), ks in small.items():
-        order = list(reversed(ks)) if cfg.reverse_order else list(ks)
-        sizes = [leaves_p[k][1].size * _itemsize(dtype) for k in order]
-        groups.append({
-            "group": name, "dtype": dtype,
-            "buckets": [[order[i] for i in bucket]
-                        for bucket in partition_buckets(sizes, bucket_bytes)],
-        })
-    return large, groups
+        singles.reverse()
+    plan = [_Exchange("per_leaf", g, dtypes[g], [k]) for g, k in singles]
+    for g, ks in pools.items():
+        order = ks[::-1] if cfg.reverse_order else ks
+        sizes = [leaves_p[k][1].size * _itemsize(dtypes[g]) for k in order]
+        plan += [_Exchange("fused" if cfg.fuse else "grouped", g, dtypes[g],
+                           [order[i] for i in bucket])
+                 for bucket in partition_buckets(sizes, bucket_bytes)]
+    return plan
 
 
 def bucket_layout(grads, cfg: GradSyncConfig = GradSyncConfig()) -> list[dict]:
@@ -237,60 +233,68 @@ def bucket_layout(grads, cfg: GradSyncConfig = GradSyncConfig()) -> list[dict]:
     ``fuse=False`` one ``"per_leaf"`` entry per large leaf plus
     ``"grouped"`` entries for the shared small-leaf buckets. Works on
     concrete arrays or ShapeDtypeStructs; never traces. Used by the
-    dry-run audit and the bucket-sweep benchmark to cross-check the HLO
-    against the intended schedule.
+    dry-run audit and bucket sweep to cross-check the HLO against the
+    intended schedule.
     """
     leaves_p, _ = jax.tree_util.tree_flatten_with_path(grads)
-    out = []
-    if cfg.fuse:
-        bucket_bytes = _require_resolved(cfg.bucket_bytes)
-        for name, (idx_group, dtype) in zip(
-                ("comm", "fp32"), _precision_groups(leaves_p, cfg)):
-            if not idx_group:
-                continue
-            order = (list(reversed(idx_group)) if cfg.reverse_order
-                     else list(idx_group))
-            sizes = [leaves_p[k][1].size * _itemsize(dtype) for k in order]
-            for bucket in partition_buckets(sizes, bucket_bytes):
-                ks = [order[i] for i in bucket]
-                out.append({
-                    "group": name,
-                    "dtype": np.dtype(dtype).name,
-                    "nbytes": sum(sizes[i] for i in bucket),
-                    "num_leaves": len(ks),
-                    "paths": [_path_str(leaves_p[k][0]) for k in ks],
-                    "mode": "fused",
-                })
-        return out
-    large, groups = _per_leaf_plan(leaves_p, cfg)
-    for k, dtype in large:
-        path, leaf = leaves_p[k]
-        name, _ = _per_leaf_dtype(path, leaf, cfg)
-        out.append({
-            "group": name, "dtype": np.dtype(dtype).name,
-            "nbytes": leaf.size * _itemsize(dtype), "num_leaves": 1,
-            "paths": [_path_str(path)], "mode": "per_leaf",
-        })
-    for grp in groups:
-        for ks in grp["buckets"]:
-            out.append({
-                "group": grp["group"],
-                "dtype": np.dtype(grp["dtype"]).name,
-                "nbytes": sum(leaves_p[k][1].size * _itemsize(grp["dtype"])
-                              for k in ks),
-                "num_leaves": len(ks),
-                "paths": [_path_str(leaves_p[k][0]) for k in ks],
-                "mode": "grouped",
-            })
-    return out
+    return [{
+        "group": ex.group,
+        "dtype": np.dtype(ex.dtype).name,
+        "nbytes": sum(leaves_p[k][1].size for k in ex.leaves)
+        * _itemsize(ex.dtype),
+        "num_leaves": len(ex.leaves),
+        "paths": [_path_str(leaves_p[k][0]) for k in ex.leaves],
+        "mode": ex.mode,
+    } for ex in _plan(leaves_p, cfg)]
 
 
 def sync_tree(grads, grid: TorusGrid, cfg: GradSyncConfig = GradSyncConfig()):
-    """All-reduce (mean if cfg.mean) a gradient pytree over the DP grid."""
-    _require_resolved(cfg.bucket_bytes)
-    if cfg.fuse:
-        return _sync_fused(grads, grid, cfg)
-    return _sync_per_leaf(grads, grid, cfg)
+    """All-reduce (mean if cfg.mean) a gradient pytree over the DP grid,
+    issuing the exchanges of ``bucket_layout`` in its order."""
+    leaves_p, treedef = jax.tree_util.tree_flatten_with_path(grads)
+    plan = _plan(leaves_p, cfg)
+    if not leaves_p:
+        return grads
+    scale = 1.0 / _world(grid) if cfg.mean else 1.0
+    mult = _ring_multiple(grid)
+    leaves = [leaf for _, leaf in leaves_p]
+    out: list = [None] * len(leaves)
+    for ex in plan:
+        if ex.mode == "per_leaf":
+            # one strategy exchange along the leading dim, so model-axis
+            # sharding on the other dims is untouched
+            (k,) = ex.leaves
+            g = leaves[k].astype(ex.dtype) * jnp.asarray(scale, ex.dtype)
+            g = collectives.all_reduce(_pad_to(g, mult), grid, cfg.strategy,
+                                       cfg.lowering)
+            out[k] = g[:leaves[k].shape[0]].astype(leaves[k].dtype)
+        else:
+            _exchange_flat(ex, leaves, out, scale, mult, grid, cfg)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _exchange_flat(ex: _Exchange, leaves, out, scale, mult, grid: TorusGrid,
+                   cfg: GradSyncConfig):
+    """One raveled buffer of ``ex.leaves``: concatenate, pre-scale, reduce,
+    and split the result back into ``out``."""
+    flat = jnp.concatenate(
+        [jnp.ravel(leaves[k]).astype(ex.dtype) for k in ex.leaves])
+    # pre-scale: keeps fp16/bf16 partial sums in range (paper exchanges in
+    # half precision)
+    flat = flat * jnp.asarray(scale, ex.dtype)
+    if ex.mode == "fused":
+        reduced = collectives.all_reduce(_pad_to(flat, mult), grid,
+                                         cfg.strategy, cfg.lowering)
+        reduced = reduced[: flat.shape[0]]
+    else:
+        # grouped small leaves: one latency-amortized psum per buffer
+        reduced = lax.psum(flat, grid.axes)
+    off = 0
+    for k in ex.leaves:
+        size = leaves[k].size
+        out[k] = reduced[off: off + size].reshape(
+            leaves[k].shape).astype(leaves[k].dtype)
+        off += size
 
 
 def record_bucket_metrics(grads_like, cfg: GradSyncConfig,
@@ -506,85 +510,3 @@ def resolve_sync_config(cfg: GradSyncConfig, grid: TorusGrid, mesh,
     resolved, tune_events = _resolve_bucket_bytes(
         resolved, grid, mesh, params_like, hw, context)
     return resolved, events + tune_events
-
-
-def _sync_fused(grads, grid: TorusGrid, cfg: GradSyncConfig):
-    leaves_p, treedef = jax.tree_util.tree_flatten_with_path(grads)
-    if not leaves_p:
-        return grads
-    world = _world(grid)
-    scale = 1.0 / world if cfg.mean else 1.0
-    mult = _ring_multiple(grid)
-
-    leaves = [leaf for _, leaf in leaves_p]
-    out: list = [None] * len(leaves)
-
-    for idx_group, dtype in _precision_groups(leaves_p, cfg):
-        if not idx_group:
-            continue
-        # reverse-backprop order: tree-flatten order tracks the forward
-        # pass, so the last leaves' grads materialize first in backward --
-        # their bucket is issued first and overlaps the rest of backprop.
-        order = list(reversed(idx_group)) if cfg.reverse_order else list(idx_group)
-        sizes = [leaves[k].size * _itemsize(dtype) for k in order]
-        for bucket in partition_buckets(sizes, cfg.bucket_bytes):
-            ks = [order[i] for i in bucket]
-            flat = jnp.concatenate(
-                [jnp.ravel(leaves[k]).astype(dtype) for k in ks])
-            # pre-scale: keeps fp16/bf16 partial sums in range (paper
-            # exchanges in half precision)
-            flat = flat * jnp.asarray(scale, dtype)
-            padded = _pad_to(flat, mult)
-            reduced = collectives.all_reduce(padded, grid, cfg.strategy,
-                                             cfg.lowering)
-            reduced = reduced[: flat.shape[0]]
-            off = 0
-            for k in ks:
-                size = leaves[k].size
-                out[k] = reduced[off: off + size].reshape(
-                    leaves[k].shape).astype(leaves[k].dtype)
-                off += size
-
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def _sync_per_leaf(grads, grid: TorusGrid, cfg: GradSyncConfig):
-    leaves_p, treedef = jax.tree_util.tree_flatten_with_path(grads)
-    if not leaves_p:
-        return grads
-    world = _world(grid)
-    scale = 1.0 / world if cfg.mean else 1.0
-    mult = _ring_multiple(grid)
-    leaves = [leaf for _, leaf in leaves_p]
-    out: list = [None] * len(leaves)
-
-    large, groups = _per_leaf_plan(leaves_p, cfg)
-    # Large (possibly model-sharded) leaves: one strategy exchange each
-    # along the leading dim, in reverse-backprop issue order.
-    for k, dtype in large:
-        g = leaves[k]
-        orig_dtype = g.dtype
-        g = g.astype(dtype) * jnp.asarray(scale, dtype)
-        n0 = g.shape[0]
-        g = _pad_to(g, mult)
-        g = collectives.all_reduce(g, grid, cfg.strategy, cfg.lowering)
-        out[k] = g[:n0].astype(orig_dtype)
-
-    # Small replicated leaves: ravel into shared buffers per precision
-    # group (partitioned by bucket_bytes), one latency-amortized psum per
-    # bucket instead of one per leaf.
-    for grp in groups:
-        dtype = grp["dtype"]
-        for ks in grp["buckets"]:
-            flat = jnp.concatenate(
-                [jnp.ravel(leaves[k]).astype(dtype) for k in ks])
-            flat = flat * jnp.asarray(scale, dtype)
-            reduced = lax.psum(flat, grid.axes)
-            off = 0
-            for k in ks:
-                size = leaves[k].size
-                out[k] = reduced[off: off + size].reshape(
-                    leaves[k].shape).astype(leaves[k].dtype)
-                off += size
-
-    return jax.tree_util.tree_unflatten(treedef, out)

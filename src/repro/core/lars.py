@@ -98,21 +98,3 @@ def update(params, grads, opt_state, *, lr, momentum, cfg: LARSConfig = LARSConf
         jax.tree_util.tree_structure(mom_tree), new_m)
     return params_out, {"momentum": mom_out}
 
-
-# -- plain momentum-SGD baseline (reference configuration uses LARS too, but
-#    benchmarks compare against this for the no-LARS ablation) --------------
-
-def sgd_init(params):
-    return {"momentum": jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)}
-
-
-def sgd_update(params, grads, opt_state, *, lr, momentum, weight_decay=0.0):
-    def upd(p, g, v):
-        g32 = g.astype(jnp.float32) + weight_decay * p.astype(jnp.float32)
-        v_new = momentum * v + g32
-        return (p.astype(jnp.float32) - lr * v_new).astype(p.dtype), v_new
-
-    flat = jax.tree.map(upd, params, grads, opt_state["momentum"])
-    new_p = jax.tree.map(lambda t: t[0], flat, is_leaf=lambda t: isinstance(t, tuple))
-    new_v = jax.tree.map(lambda t: t[1], flat, is_leaf=lambda t: isinstance(t, tuple))
-    return new_p, {"momentum": new_v}

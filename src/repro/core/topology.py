@@ -86,8 +86,7 @@ def select_grid(dp_axes: Sequence[str]) -> TorusGrid:
         raise ValueError("at least one data-parallel axis required")
     if len(dp_axes) == 1:
         # degenerate: no second axis to split over -- callers who want a true
-        # 2D torus on one axis should build a factorized mesh (see
-        # launch/mesh.py make_factorized_mesh).
+        # 2D torus should give the mesh two data-parallel axes.
         return TorusGrid(h_axes=dp_axes, v_axes=())
     return TorusGrid(h_axes=(dp_axes[-1],), v_axes=tuple(dp_axes[:-1]))
 

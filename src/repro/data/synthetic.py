@@ -5,8 +5,7 @@ Two generators:
 * ``SyntheticImageNet`` -- class-conditional Gaussian-blob images: each of
   the K classes has a fixed random template; a sample is template + noise.
   Linear-separable enough that ResNet training shows real convergence
-  signal (benchmarks/convergence.py reproduces the paper's Table-5
-  *relative* effects: LS and batch-size control vs baseline).
+  signal.
 * ``SyntheticTokens`` -- order-2 Markov token stream with a fixed random
   transition matrix; gives language-model training a learnable signal.
 
@@ -21,7 +20,6 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,12 +1,11 @@
 """Flash attention forward kernel (Pallas, TPU target).
 
-The roofline analysis (EXPERIMENTS.md §Roofline) shows prefill/train
-steps are MEMORY-dominated for every attention arch: the chunked-jnp
-attention materializes (q_chunk, S) fp32 logits + softmax weights per
-layer in HBM. This kernel keeps the running max / sum / output accumulator
-in VMEM across k-blocks (online softmax), so per (q-block, k-block) tile
-only the (bq, bk) logits live in VMEM and logits NEVER touch HBM:
-HBM traffic drops from O(S^2) to O(S * D) per head.
+The chunked-jnp attention materializes (q_chunk, S) fp32 logits +
+softmax weights per layer in HBM. This kernel keeps the running max /
+sum / output accumulator in VMEM across k-blocks (online softmax), so
+per (q-block, k-block) tile only the (bq, bk) logits live in VMEM and
+logits NEVER touch HBM: HBM traffic drops from O(S^2) to O(S * D) per
+head.
 
 Layout: q/k/v as (B, H, S, D) (heads-major so a (b, h) pair is a grid
 row); GQA is handled by the wrapper (kv head index = h // group). Causal

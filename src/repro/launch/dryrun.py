@@ -137,8 +137,8 @@ def build_train(arch_id, cfg, shape, mesh, sync_strategy="torus2d",
         # bf16 (paper: fp16), but XLA's *CPU* AllReducePromotion pass
         # crashes on bf16 partial all-reduces over model-sharded operands
         # ("Invalid binary instruction opcode copy"); on CPU we lower the
-        # exchange in f32 and the roofline applies the documented /2
-        # correction for gradient traffic (EXPERIMENTS.md §Roofline).
+        # exchange in f32, so the CPU compile's gradient-exchange bytes are
+        # twice what the chip's bf16 exchange moves.
         comm_dtype = (jnp.bfloat16 if jax.default_backend() == "tpu"
                       else jnp.float32)
         grid = select_grid(dp)

@@ -3,9 +3,9 @@
 ``collective_bytes`` is not in ``compiled.cost_analysis()``; we recover it
 from the optimized HLO: every all-reduce / all-gather / reduce-scatter /
 all-to-all / collective-permute op's *output* bytes are summed per op kind
-(output bytes == bytes received per device, the roofline-relevant number;
-for reduce-scatter the on-wire volume per device is (n-1)/n of the input --
-we report output bytes and note the convention in EXPERIMENTS.md).
+(output bytes == bytes received per device;
+for reduce-scatter the on-wire volume per device is (n-1)/n of the input,
+which ``wire_bytes`` reports beside the output bytes).
 """
 
 from __future__ import annotations
@@ -23,11 +23,16 @@ _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
 # e.g.:  %all-gather.3 = bf16[16,4096]{1,0} all-gather(...)
-#        ROOT %r = (f32[8]{0}, f32[8]{0}) tuple(...)
+#        %all-reduce = (f32[128,8]{1,0}, f32[128,8]{1,0}) all-reduce(...)
+# The second is what XLA's combiner makes of several collectives: each
+# element of the tuple is one of them. (An async all-gather-start or
+# collective-permute-start also lists its operands in the tuple; only TPU
+# programs have those, and they are counted with ``collective_counts``.)
 _OP_RE = re.compile(
-    r"=\s*(?:\()?\s*([a-z0-9]+)\[([\d,]*)\][^ ]*\s*"
+    r"=\s*(\([^=\n]*?\)|[a-z0-9]+\[[\d,]*\]\S*)\s+"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(?:-start)?\(([^\n]*)", re.M)
+_ARRAY_RE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 
 _GROUPS_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
@@ -71,16 +76,23 @@ def _nbytes(dtype: str, dims: str) -> int:
     return n
 
 
+def _collective_ops(hlo_text: str):
+    """(kind, dtype, dims, rest) per collective, one per tuple element."""
+    for m in _OP_RE.finditer(hlo_text):
+        shape, kind, rest = m.groups()
+        for a in _ARRAY_RE.finditer(shape):
+            yield kind, a.group(1), a.group(2), rest
+
+
 def collective_stats(hlo_text: str) -> dict:
     """-> {op_kind: {"count", "bytes"}, "by_dtype": {dt: bytes},
-    "total_bytes", "total_count"}. Per-dtype split lets the roofline apply
-    the f32->bf16 exchange correction for the CPU-lowered gradient sync."""
+    "total_bytes", "total_count"}. The per-dtype split separates the CPU
+    dry-run's f32 gradient exchange, which moves twice the chip's bf16 bytes."""
     out: dict = {k: {"count": 0, "bytes": 0, "wire_bytes": 0.0}
                  for k in _COLLECTIVES}
     by_dtype: dict[str, int] = defaultdict(int)
     wire_by_dtype: dict[str, float] = defaultdict(float)
-    for m in _OP_RE.finditer(hlo_text):
-        dtype, dims, kind, rest = m.groups()
+    for kind, dtype, dims, rest in _collective_ops(hlo_text):
         nb = _nbytes(dtype, dims)
         wb = _wire_bytes(kind, nb, _group_size(rest))
         out[kind]["count"] += 1
@@ -119,13 +131,9 @@ def collective_schedule(hlo_text: str) -> list[dict]:
     Unlike :func:`collective_stats` (aggregates), this keeps the per-op
     sequence so a bucketed gradient exchange can be audited op by op.
     """
-    out = []
-    for m in _OP_RE.finditer(hlo_text):
-        dtype, dims, kind, rest = m.groups()
-        out.append({"kind": kind, "dtype": dtype,
-                    "nbytes": _nbytes(dtype, dims),
-                    "group_size": _group_size(rest)})
-    return out
+    return [{"kind": kind, "dtype": dtype, "nbytes": _nbytes(dtype, dims),
+             "group_size": _group_size(rest)}
+            for kind, dtype, dims, rest in _collective_ops(hlo_text)]
 
 
 def bucket_audit(hlo_text: str, min_bytes: int = 0) -> dict:
@@ -173,11 +181,3 @@ def bucket_audit(hlo_text: str, min_bytes: int = 0) -> dict:
         },
     }
 
-
-def op_histogram(hlo_text: str, top: int = 15) -> list[tuple[str, int]]:
-    """Rough instruction histogram (op name -> count) for schedule audits."""
-    counts: dict[str, int] = defaultdict(int)
-    for m in re.finditer(r"=\s*(?:\()?[a-z0-9]+\[[^\]]*\][^ ]*\s*([a-z][\w-]*)\(",
-                         hlo_text):
-        counts[m.group(1)] += 1
-    return sorted(counts.items(), key=lambda kv: -kv[1])[:top]

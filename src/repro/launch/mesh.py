@@ -3,11 +3,6 @@
 ``make_production_mesh``: the fixed target -- 16x16 = 256 chips per pod
 (``data`` x ``model``), 2 pods = 512 chips multi-pod (``pod`` axis leading).
 
-``make_factorized_mesh``: the framework's native expression of the paper's
-2D-Torus *within* a pod -- the data axis split into (data_y, data_x) rings
-so the torus phases map onto two physical ICI dimensions (paper Table 4
-grids). Used by the perf experiments; the dry-run keeps the contract mesh.
-
 ``param_pspecs``: path-based sharding rules (megatron-style TP over
 ``model`` + optional fsdp over ``data``). Scanned-block leaves carry a
 leading (n_blocks,) dim -> specs are right-aligned against leaf rank.
@@ -26,12 +21,6 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return jax.make_mesh(shape, axes)
-
-
-def make_factorized_mesh(*, data_y: int = 4, data_x: int = 4,
-                         model: int = 16):
-    """Single-pod mesh with the data axis factorized into the 2D torus."""
-    return jax.make_mesh((data_y, data_x, model), ("data_y", "data_x", "model"))
 
 
 def dp_axes_of(mesh) -> tuple[str, ...]:

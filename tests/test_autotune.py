@@ -11,9 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import autotune, collectives
-from repro.core.autotune import (HardwareModel, LEGACY_DEFAULT_BUCKET_BYTES,
-                                 TPU_POD_HW, analytic_knee_bytes,
+from repro.core import collectives
+from repro.core.autotune import (LEGACY_DEFAULT_BUCKET_BYTES, TPU_POD_HW,
+                                 analytic_knee_bytes,
                                  candidate_bucket_bytes, pick_within_bracket,
                                  recommend_bucket_bytes, refine_from_sweep,
                                  sweep_bracket)

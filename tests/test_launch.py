@@ -2,7 +2,6 @@
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
@@ -87,16 +86,25 @@ def test_hlo_stats_parsing():
       %ar.1 = f32[1024]{0} all-reduce-start(%y), to_apply=%add
       %rs = bf16[256,16]{1,0} reduce-scatter(%z)
       %cp = f32[8,8]{1,0} collective-permute(%w)
+      %ct = (f32[128,8]{1,0}, f32[128,8], bf16[64]) all-reduce(%a, %b, %c), replica_groups={{0,1,2}}
       ROOT %t = (f32[8]{0}) tuple(%cp)
     """
     st = hlo_stats.collective_stats(text)
     assert st["all-gather"]["count"] == 1
     assert st["all-gather"]["bytes"] == 16 * 4096 * 2
-    assert st["all-reduce"]["count"] == 1
-    assert st["all-reduce"]["bytes"] == 1024 * 4
+    # the combined all-reduce counts as the three it was made from
+    assert st["all-reduce"]["count"] == 4
+    assert st["all-reduce"]["bytes"] == 1024 * 4 + 2 * 128 * 8 * 4 + 64 * 2
     assert st["reduce-scatter"]["bytes"] == 256 * 16 * 2
-    assert st["by_dtype"]["f32"] == 1024 * 4 + 64 * 4
-    assert st["total_count"] == 4
+    assert st["by_dtype"]["f32"] == 1024 * 4 + 64 * 4 + 2 * 128 * 8 * 4
+    assert st["by_dtype"]["bf16"] == 16 * 4096 * 2 + 256 * 16 * 2 + 64 * 2
+    assert st["total_count"] == 7
+    sched = hlo_stats.collective_schedule(text)
+    assert [op["kind"] for op in sched] == [
+        "all-gather", "all-reduce", "reduce-scatter", "collective-permute",
+        "all-reduce", "all-reduce", "all-reduce"]
+    assert [op["nbytes"] for op in sched[-3:]] == [4096, 4096, 128]
+    assert {op["group_size"] for op in sched[-3:]} == {3}
 
 
 def test_bucket_audit_reports_dropped_ops():

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from _hyp import given, settings, strategies as st
 
-from repro.core import lars, losses, schedules
+from repro.core import lars, losses
 from repro.core.batch_control import build_plan, epoch_of
 from repro.core.schedules import BatchSchedule, BatchStage, ConfigA, ConfigB, paper_schedule
 
